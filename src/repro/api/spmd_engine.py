@@ -68,7 +68,8 @@ from repro.api.engines import (SessionContext, cohort_layout,
 from repro.api.fused_engine import FusedEngine
 from repro.core.splitee import stack_pytrees
 from repro.data.pipeline import effective_batch_size
-from repro.launch.mesh import axis_sizes, batch_axes, lane_axis
+from repro.launch.mesh import (axis_sizes, batch_axes, lane_axis,
+                               make_host_mesh)
 from repro.launch.shardings import (resolve_recipe, stage_batch_spec,
                                     to_named, train_state_specs)
 from repro.optim import adam_init
@@ -78,8 +79,7 @@ def default_data_mesh():
     """A 1-D data-parallel mesh over every visible device (the host-CPU
     test topology and the single-process accelerator default).  Production
     launches pass ``launch.mesh.make_production_mesh()`` instead."""
-    n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return make_host_mesh((len(jax.devices()), 1))
 
 
 def resolve_mesh(ctx: SessionContext):

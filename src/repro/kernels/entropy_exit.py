@@ -9,10 +9,13 @@ per row:
     H = m + log S - U/S        (since H = log Z - E[x])
 Rescaling on a new max multiplies S and U by e^{m_old - m_new}.
 Grid = (row blocks, vocab blocks); vocab axis sequential with VMEM scratch.
+The per-row state and outputs are ``(rows, 1)`` columns: Mosaic lowers no
+rank-1 ``(block_rows,)`` block narrower than 128 lanes.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -37,13 +40,13 @@ def _entropy_kernel(tau_ref, x_ref, h_ref, exit_ref, m_scr, s_scr, u_scr, *,
     col = iv * block_v + jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
     x = jnp.where(col < vocab, x, NEG_INF)
 
-    m_prev = m_scr[...]
-    m_new = jnp.maximum(m_prev, jnp.max(x, axis=1))
+    m_prev = m_scr[...]                                      # (Br, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(x, axis=1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(x - m_new[:, None])
+    p = jnp.exp(x - m_new)
     # padded lanes have p == exp(NEG_INF - m) == 0, so they contribute nothing
-    s_scr[...] = s_scr[...] * alpha + jnp.sum(p, axis=1)
-    u_scr[...] = u_scr[...] * alpha + jnp.sum(p * x, axis=1)
+    s_scr[...] = s_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+    u_scr[...] = u_scr[...] * alpha + jnp.sum(p * x, axis=1, keepdims=True)
     m_scr[...] = m_new
 
     @pl.when(iv == nv - 1)
@@ -56,34 +59,40 @@ def _entropy_kernel(tau_ref, x_ref, h_ref, exit_ref, m_scr, s_scr, u_scr, *,
 
 def entropy_exit_pallas(logits: jnp.ndarray, tau: jnp.ndarray, *,
                         block_rows: int = 8, block_v: int = 2048,
+                        vocab: Optional[int] = None,
                         interpret: bool = False):
-    """logits: (B, V) -> (entropy (B,) f32, exit (B,) int32 0/1).
-    B must be a multiple of block_rows (ops.py pads).  ``tau`` is a traced
-    (1, 1) float32 scalar living in SMEM — threshold sweeps (the paper's
-    Fig. 2 axis) reuse one compilation."""
+    """logits: (B, V) -> (entropy (B, 1) f32, exit (B, 1) int32 0/1).
+    B must be a multiple of block_rows (ops.py pads).  ``vocab`` is the true
+    (pre-padding) vocabulary: columns at or past it are masked.  ``tau`` is
+    a traced (1, 1) float32 scalar living in SMEM — threshold sweeps (the
+    paper's Fig. 2 axis) reuse one compilation."""
     B, V = logits.shape
-    assert B % block_rows == 0
+    if B % block_rows != 0:
+        raise ValueError(f"unpadded batch: B={B} (logits {logits.shape}) "
+                         f"must be a multiple of block_rows={block_rows}; "
+                         f"route through ops.entropy_exit for padding")
+    vocab = V if vocab is None else vocab
     tau = jnp.asarray(tau, jnp.float32).reshape(1, 1)
     nv = (V + block_v - 1) // block_v
     grid = (B // block_rows, nv)
-    kernel = functools.partial(_entropy_kernel, vocab=V, block_v=block_v)
+    kernel = functools.partial(_entropy_kernel, vocab=vocab, block_v=block_v)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec((block_rows, block_v), lambda r, iv: (r, iv))],
         out_specs=[
-            pl.BlockSpec((block_rows,), lambda r, iv: (r,)),
-            pl.BlockSpec((block_rows,), lambda r, iv: (r,)),
+            pl.BlockSpec((block_rows, 1), lambda r, iv: (r, 0)),
+            pl.BlockSpec((block_rows, 1), lambda r, iv: (r, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((B,), jnp.float32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
+            jax.ShapeDtypeStruct((B, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, 1), jnp.int32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_rows,), jnp.float32),
-            pltpu.VMEM((block_rows,), jnp.float32),
-            pltpu.VMEM((block_rows,), jnp.float32),
+            pltpu.VMEM((block_rows, 1), jnp.float32),
+            pltpu.VMEM((block_rows, 1), jnp.float32),
+            pltpu.VMEM((block_rows, 1), jnp.float32),
         ],
         interpret=interpret,
     )(tau, logits)

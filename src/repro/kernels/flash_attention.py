@@ -33,6 +33,11 @@ cheap elementwise pass, the FA-2 split).
 Default blocks (128, 128): MXU-aligned (contracting/lane dims multiples of
 128); working set 4 x 128x128 x 4B ~= 256 KiB << 16 MiB VMEM, leaving head
 room for double buffering.
+
+Per-row quantities (running max/denominator, the saved logsumexp, the
+backward's delta) are carried lane-replicated as ``(rows, LANES)`` tiles:
+Mosaic only accepts blocks whose last two dims are (8, 128)-aligned or
+whole, so a rank-1 ``(block_q,)`` row block cannot be lowered for the TPU.
 """
 from __future__ import annotations
 
@@ -46,6 +51,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+#: lane width of the per-row (max, denominator, lse, delta) tiles
+LANES = 128
 
 
 def _block_run(k_start, q_start, *, block_q: int, block_k: int,
@@ -119,12 +126,12 @@ def _flash_kernel(kv_ref, q_ref, k_ref, v_ref, o_ref, *rest,
         mask = jnp.logical_and(mask, kpos < kv_len)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_prev = m_scr[...]                                  # (Bq, LANES)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1)
-        acc_scr[...] = (acc_scr[...] * alpha[:, None]
+        p = jnp.exp(s - m_new[:, :1])
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = (acc_scr[...] * alpha[:, :1]
                         + jax.lax.dot_general(
                             p, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32))
@@ -133,7 +140,7 @@ def _flash_kernel(kv_ref, q_ref, k_ref, v_ref, o_ref, *rest,
     @pl.when(ik == nk - 1)
     def _finalize():
         denom = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0, ...] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0, ...] = (acc_scr[...] / denom[:, :1]).astype(o_ref.dtype)
         if emit_lse:
             lse_ref[0, 0, ...] = m_scr[...] + jnp.log(denom)
 
@@ -154,7 +161,8 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     ``kpos >= kv_len`` on top of the static masks — the decode ring-buffer
     valid prefix, varying per step without recompilation.  With
     ``return_lse`` also returns the per-row logsumexp of the scaled scores
-    (B, H, Tq) fp32 — the backward kernels' residual."""
+    (B, H, Tq, LANES) fp32, replicated across the lanes — the backward
+    kernels' residual."""
     B, H, Tq, D = q.shape
     _, Hkv, Tk, _ = k.shape
     _check_qkv_shapes(q, k, v, block_q, block_k)
@@ -177,9 +185,10 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                               lambda b, h, iq, ik: (b, h, iq, 0))]
     out_shape = [jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype)]
     if return_lse:
-        out_specs.append(pl.BlockSpec((1, 1, block_q),
-                                      lambda b, h, iq, ik: (b, h, iq)))
-        out_shape.append(jax.ShapeDtypeStruct((B, H, Tq), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, block_q, LANES),
+                                      lambda b, h, iq, ik: (b, h, iq, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((B, H, Tq, LANES),
+                                              jnp.float32))
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -192,8 +201,8 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
@@ -236,8 +245,8 @@ def _rebuild_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0, 0].astype(jnp.float32)                      # (Bk, D)
     v = v_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0].astype(jnp.float32)                  # (Bq,)
-    delta = delta_ref[0, 0].astype(jnp.float32)
+    lse = lse_ref[0, 0][:, :1].astype(jnp.float32)           # (Bq, 1)
+    delta = delta_ref[0, 0][:, :1].astype(jnp.float32)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -245,10 +254,10 @@ def _rebuild_p_ds(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                       window=window, seq_k=seq_k)
     # exact-zero masked entries: padded q rows (do == 0) and fully-masked
     # rows contribute nothing, with no exp blow-up from a garbage lse
-    p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)      # (Bq, Bk)
+    p = jnp.where(mask, jnp.exp(s - lse), 0.0)               # (Bq, Bk)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     return q, k, do, p, ds
 
 
@@ -317,8 +326,8 @@ def flash_attention_bwd_dkv_pallas(q, k, v, do, lse, delta, *,
                                    seq_k: Optional[int] = None,
                                    interpret: bool = False):
     """dK/dV: kv-block axis outer, (GQA group x q-block) inner-sequential.
-    ``lse``/``delta`` are (B, H, Tq) fp32 — the forward's logsumexp and
-    rowsum(dO * O).  Returns ``(dk, dv)`` in (B, Hkv, Tk, D) fp32, the
+    ``lse``/``delta`` are (B, H, Tq, LANES) fp32, lane-replicated — the
+    forward's logsumexp and rowsum(dO * O).  Returns ``(dk, dv)`` in (B, Hkv, Tk, D) fp32, the
     GQA group already summed in-kernel."""
     B, H, Tq, D = q.shape
     _, Hkv, Tk, _ = k.shape
@@ -333,7 +342,7 @@ def flash_attention_bwd_dkv_pallas(q, k, v, do, lse, delta, *,
         block_k=block_k, causal=causal, window=window, seq_k=seq_k)
 
     q_index = lambda b, hkv, ik, ig, iq: (b, hkv * G + ig, iq, 0)
-    row_index = lambda b, hkv, ik, ig, iq: (b, hkv * G + ig, iq)
+    row_index = lambda b, hkv, ik, ig, iq: (b, hkv * G + ig, iq, 0)
     kv_index = lambda b, hkv, ik, ig, iq: (b, hkv, ik, 0)
     return pl.pallas_call(
         kernel,
@@ -343,8 +352,8 @@ def flash_attention_bwd_dkv_pallas(q, k, v, do, lse, delta, *,
             pl.BlockSpec((1, 1, block_k, D), kv_index),
             pl.BlockSpec((1, 1, block_k, D), kv_index),
             pl.BlockSpec((1, 1, block_q, D), q_index),
-            pl.BlockSpec((1, 1, block_q), row_index),
-            pl.BlockSpec((1, 1, block_q), row_index),
+            pl.BlockSpec((1, 1, block_q, LANES), row_index),
+            pl.BlockSpec((1, 1, block_q, LANES), row_index),
         ],
         out_specs=[pl.BlockSpec((1, 1, block_k, D), kv_index),
                    pl.BlockSpec((1, 1, block_k, D), kv_index)],
@@ -374,7 +383,7 @@ def flash_attention_bwd_dq_pallas(q, k, v, do, lse, delta, *,
         causal=causal, window=window, seq_k=seq_k)
 
     q_index = lambda b, h, iq, ik: (b, h, iq, 0)
-    row_index = lambda b, h, iq, ik: (b, h, iq)
+    row_index = lambda b, h, iq, ik: (b, h, iq, 0)
     kv_index = lambda b, h, iq, ik: (b, h * Hkv // H, ik, 0)
     return pl.pallas_call(
         kernel,
@@ -384,8 +393,8 @@ def flash_attention_bwd_dq_pallas(q, k, v, do, lse, delta, *,
             pl.BlockSpec((1, 1, block_k, D), kv_index),
             pl.BlockSpec((1, 1, block_k, D), kv_index),
             pl.BlockSpec((1, 1, block_q, D), q_index),
-            pl.BlockSpec((1, 1, block_q), row_index),
-            pl.BlockSpec((1, 1, block_q), row_index),
+            pl.BlockSpec((1, 1, block_q, LANES), row_index),
+            pl.BlockSpec((1, 1, block_q, LANES), row_index),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, D), q_index),
         out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), jnp.float32),

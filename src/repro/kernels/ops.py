@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.entropy_exit import entropy_exit_pallas
-from repro.kernels.flash_attention import (flash_attention_bwd_dkv_pallas,
+from repro.kernels.flash_attention import (LANES,
+                                           flash_attention_bwd_dkv_pallas,
                                            flash_attention_bwd_dq_pallas,
                                            flash_attention_pallas)
 from repro.kernels.rwkv_wkv import (rwkv_wkv_bwd_pallas, rwkv_wkv_fwd_pallas,
@@ -111,7 +112,7 @@ def flash_attention_fwd(q, k, v, *, causal: bool = True,
                                       window=window, block_q=bq, block_k=bk,
                                       seq_k=Tk, return_lse=True,
                                       interpret=interpret)
-    return out[:, :, :Tq], lse[:, :, :Tq]
+    return out[:, :, :Tq], lse[:, :, :Tq, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
@@ -141,8 +142,10 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, causal: bool = True,
     kp, _ = _pad_to(k, 2, bk)
     vp, _ = _pad_to(v, 2, bk)
     dop, _ = _pad_to(do.astype(q.dtype), 2, bq)   # padded q rows: do == 0
-    lsep, _ = _pad_to(lse, 2, bq)
-    deltap, _ = _pad_to(delta, 2, bq)
+    # the kernels read the per-row residuals as lane-replicated tiles
+    lsep, deltap = (jnp.broadcast_to(_pad_to(a, 2, bq)[0][..., None],
+                                     qp.shape[:3] + (LANES,))
+                    for a in (lse, delta))
     common = dict(causal=causal, window=window, block_q=bq, block_k=bk,
                   seq_k=Tk, interpret=interpret)
     dk, dv = flash_attention_bwd_dkv_pallas(qp, kp, vp, dop, lsep, deltap,
@@ -165,12 +168,14 @@ def entropy_exit(logits, tau, *, block_rows: int = 8,
         raise ValueError(f"entropy_exit expects rank-2 (B, V) logits, got "
                          f"{logits.shape}")
     B, V = logits.shape
-    br = min(block_rows, B) if B % min(block_rows, B) == 0 else 1
-    xp, pb = _pad_to(logits, 0, br)
-    bv = min(block_v, max(128, V))
-    H, ex = entropy_exit_pallas(xp, tau, block_rows=br, block_v=bv,
-                                interpret=interpret)
-    return H[:B], ex[:B].astype(bool)
+    # rows pad to whole row blocks and the vocab to whole 128-lane vocab
+    # blocks (the kernel masks columns >= V), so every block is TPU-legal
+    bv = min(block_v, -(-V // 128) * 128)
+    xp, _ = _pad_to(logits, 0, block_rows)
+    xp, _ = _pad_to(xp, 1, bv)
+    H, ex = entropy_exit_pallas(xp, tau, block_rows=block_rows, block_v=bv,
+                                vocab=V, interpret=interpret)
+    return H[:B, 0], ex[:B, 0].astype(bool)
 
 
 def _check_wkv_operands(r, k, v, log_w, u) -> None:

@@ -12,6 +12,8 @@ per-channel cumulative log-decay L_t = sum_{j<=t} log w_j:
     S'   = diag(e^{L_Q}) S + sum_i (k_i e^{L_Q - L_i}) v_i^T
 
 Grid = (B*H, T/Q); the second axis is sequential so S lives in scratch.
+The per-row bonus ``u`` (and its gradient ``du``) travels as ``(B*H, 1, K)``
+so its ``(1, K)`` block spans the array's last two dims, as Mosaic requires.
 
 The backward (:func:`rwkv_wkv_bwd_pallas`) mirrors the chunk structure in
 REVERSE: the training forward (:func:`rwkv_wkv_fwd_pallas`) additionally
@@ -35,10 +37,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+def _cumsum_rows(x):
+    """Inclusive prefix sum down the rows of a (Q, K) tile: log2(Q) rounds
+    of add-the-rows-``s``-above (Hillis-Steele), because Mosaic has no
+    lowering for ``cumsum``."""
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    s = 1
+    while s < x.shape[0]:
+        x = x + jnp.where(row >= s, pltpu.roll(x, s, 0), 0.0)
+        s *= 2
+    return x
+
+
 def _chunk_decays(lw):
     """Per-chunk decay tensors from the chunk's log-decays (Q, K):
     cumulative L, previous-token L, and the chunk-exit vector e^{L_Q}."""
-    L = jnp.cumsum(lw, axis=0)                                # (Q, K)
+    L = _cumsum_rows(lw)                                      # (Q, K)
     L_prev = L - lw
     return L, L_prev
 
@@ -65,8 +79,9 @@ def _chunk_fwd(r, k, v, lw, u, S):
                              preferred_element_type=jnp.float32)
 
     # state update: S' = diag(e^{L_Q}) S + sum_i (k_i e^{L_Q - L_i}) v_i^T
-    tail = jnp.exp(L[-1:, :] - L)                             # (Q, K)
-    s_new = (jnp.exp(L[-1])[:, None] * S
+    LQ = L[-1:, :]                                            # (1, K)
+    tail = jnp.exp(LQ - L)                                    # (Q, K)
+    s_new = (jnp.exp(LQ).T * S
              + jax.lax.dot_general(k * tail, v, (((0,), (0,)), ((), ())),
                                    preferred_element_type=jnp.float32))
     return y, s_new
@@ -160,12 +175,12 @@ def _wkv_call(r, k, v, log_w, u, *, chunk: int, interpret: bool,
         kernel,
         grid=grid,
         in_specs=[seq_spec, seq_spec, seq_spec, seq_spec,
-                  pl.BlockSpec((1, K), lambda b, c: (b, 0))],
+                  pl.BlockSpec((1, 1, K), lambda b, c: (b, 0, 0))],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, log_w, u)
+    )(r, k, v, log_w, u.reshape(BH, 1, K))
     return tuple(out) if emit_chunk_states else (out[0], out[1])
 
 
@@ -200,8 +215,9 @@ def _wkv_bwd_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, dy_ref, s0_ref,
     eLn = jnp.exp(-L)
     rw = r * eLp
     kw = k * eLn
-    eLQ = jnp.exp(L[-1])                                      # (K,)
-    tail = jnp.exp(L[-1:, :] - L)                             # (Q, K)
+    LQ = L[-1:, :]                                            # (1, K)
+    eLQ = jnp.exp(LQ)
+    tail = jnp.exp(LQ - L)                                    # (Q, K)
 
     ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     tj = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
@@ -239,25 +255,25 @@ def _wkv_bwd_kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, dy_ref, s0_ref,
     du_c = jnp.sum(r * k * db, axis=0, keepdims=True)         # (1, K)
 
     # --- log-decay path: L = cumsum(lw); L_Q = L[-1] feeds eLQ and tail ---
-    dLQ = (eLQ * jnp.sum(G * S0, axis=1)
-           + jnp.sum(d_tail * tail, axis=0))                  # (K,)
+    dLQ = (eLQ * jnp.sum(G * S0, axis=1, keepdims=True).T
+           + jnp.sum(d_tail * tail, axis=0, keepdims=True))   # (1, K)
     dL = -d_kw * kw - d_tail * tail                           # (Q, K)
     dL_prev = d_rw * rw
     A = dL + dL_prev
     row = jax.lax.broadcasted_iota(jnp.int32, A.shape, 0)
-    A = A + jnp.where(row == chunk - 1, dLQ[None, :], 0.0)
+    A = A + jnp.where(row == chunk - 1, dLQ, 0.0)
     # dlw_t = sum_{i>=t} A_i - dL_prev_t  (L_prev_t excludes its own lw_t)
-    revcum = jnp.sum(A, axis=0, keepdims=True) - jnp.cumsum(A, axis=0) + A
+    revcum = jnp.sum(A, axis=0, keepdims=True) - _cumsum_rows(A) + A
     dlw = revcum - dL_prev
 
     dr_ref[0, ...] = dr
     dk_ref[0, ...] = dk
     dv_ref[0, ...] = dv
     dlw_ref[0, ...] = dlw
-    du_ref[...] += du_c
+    du_ref[0, ...] += du_c
 
     # adjoint of this chunk's ENTRY state = previous chunk's exit adjoint
-    g_scr[...] = (eLQ[:, None] * G
+    g_scr[...] = (eLQ.T * G
                   + jax.lax.dot_general(rw, dy, (((0,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32))
 
@@ -280,7 +296,7 @@ def rwkv_wkv_bwd_pallas(r, k, v, log_w, u, dy, s0, dsT, *, chunk: int = 64,
     kernel = functools.partial(_wkv_bwd_kernel, chunk=chunk)
     # reversed chunk order: grid step c touches chunk nc-1-c
     rev_seq = pl.BlockSpec((1, chunk, K), lambda b, c: (b, nc - 1 - c, 0))
-    bh_vec = pl.BlockSpec((1, K), lambda b, c: (b, 0))
+    bh_vec = pl.BlockSpec((1, 1, K), lambda b, c: (b, 0, 0))
     outs = pl.pallas_call(
         kernel,
         grid=grid,
@@ -290,8 +306,8 @@ def rwkv_wkv_bwd_pallas(r, k, v, log_w, u, dy, s0, dsT, *, chunk: int = 64,
                   pl.BlockSpec((1, K, K), lambda b, c: (b, 0, 0))],
         out_specs=[rev_seq, rev_seq, rev_seq, rev_seq, bh_vec],
         out_shape=[jax.ShapeDtypeStruct((BH, T, K), jnp.float32)] * 4
-        + [jax.ShapeDtypeStruct((BH, K), jnp.float32)],
+        + [jax.ShapeDtypeStruct((BH, 1, K), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((K, K), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, log_w, u, dy, s0, dsT)
-    return tuple(outs)
+    )(r, k, v, log_w, u.reshape(BH, 1, K), dy, s0, dsT)
+    return tuple(outs[:4]) + (outs[4].reshape(BH, K),)

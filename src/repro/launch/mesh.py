@@ -34,6 +34,7 @@ from dataclasses import dataclass
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 #: the cohort-lane mesh axis name (see launch/shardings.py recipes)
 LANE_AXIS = "lanes"
@@ -58,6 +59,15 @@ class MeshSpec:
         return dict(zip(self.axis_names, self.axis_shape))
 
 
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+    """``jax.make_mesh`` with every axis ``Auto``: the engines place arrays
+    with ``NamedSharding`` and let the SPMD partitioner resolve mixed
+    shardings (an FSDP-sharded weight meeting a ``data``-sharded batch),
+    which ``Explicit`` axes, the default since JAX 0.9, reject.  Every mesh
+    in the repo is built here."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False, lanes: int = 1):
     """The 256-chip (single-pod) / 512-chip (multi-pod) production mesh.
     ``lanes > 1`` factors a leading cohort-lane axis out of the 16-wide
@@ -71,7 +81,7 @@ def make_production_mesh(*, multi_pod: bool = False, lanes: int = 1):
                              f"({data} chips); pick a divisor of {data}")
         shape = shape[:-2] + (lanes, data // lanes, shape[-1])
         axes = axes[:-2] + (LANE_AXIS, "data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(shape: Tuple[int, ...] = (2, 2),
@@ -80,7 +90,7 @@ def make_host_mesh(shape: Tuple[int, ...] = (2, 2),
     ``make_host_mesh((2, 2, 1), ("lanes", "data", "model"))`` on a 4-device
     host splits cohort lanes over two devices and each lane's batch over
     the other two."""
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_lane_host_mesh(lanes: int, devices: Optional[int] = None):

@@ -25,6 +25,7 @@ import numpy as np
 
 from repro import configs as configs_mod
 from repro.api.serve_session import ServeSession, resolve_serve_boundary
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.backbone import init_backbone
 
 
@@ -50,6 +51,7 @@ def main() -> None:
                          "auto = pallas on TPU, ref elsewhere")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs_mod.get(args.arch).smoke().with_(kernels=args.kernels)
     exits, cut, skip_frac = resolve_serve_boundary(cfg, args.boundary)

@@ -68,13 +68,14 @@ import numpy as np
 from repro import configs as configs_mod
 from repro.api import TrainSession
 from repro.config import HeteroProfile, OptimizerConfig, SplitEEConfig
+from repro.configs import resnet18_cifar
 from repro.core.backbone_splitee import BackboneSplitModel
 from repro.core.splitee import MLPSplitModel, ResNetSplitModel
 from repro.data.pipeline import ClientPartitioner
 from repro.data.synthetic import SyntheticImageDataset, SyntheticSeqClsDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_lane_host_mesh, make_production_mesh
 from repro.launch.shardings import NAMED_RECIPES
-from repro.models.resnet import ResNetConfig
 
 #: default hetero cut layers per model family (paper Table I spirit:
 #: clients split shallow/mid/deep)
@@ -154,15 +155,18 @@ def build_model_and_data(args, arch_cfg):
         model = MLPSplitModel(in_dim=d, hidden=64, num_classes=classes,
                               num_layers=6, seed=args.seed)
     else:
-        ds = SyntheticImageDataset(num_classes=10,
+        # the paper's CIFAR-10 ResNet-18 at full width (--smoke: the reduced
+        # width of configs/resnet18_cifar.smoke())
+        rcfg = (resnet18_cifar.smoke() if args.smoke
+                else resnet18_cifar.config("cifar10"))
+        ds = SyntheticImageDataset(num_classes=rcfg.num_classes,
                                    train_size=args.train_size,
                                    test_size=args.test_size,
-                                   image_size=16, noise=2.0, seed=args.seed)
+                                   image_size=rcfg.image_size, noise=2.0,
+                                   seed=args.seed)
         x, y = ds.train
         xt, yt = ds.test
-        model = ResNetSplitModel(ResNetConfig(num_classes=10,
-                                              width_mult=0.125,
-                                              image_size=16), seed=args.seed)
+        model = ResNetSplitModel(rcfg, seed=args.seed)
     parts = ClientPartitioner(args.clients, seed=args.seed).split(x, y)
     return model, parts, (x, y), (xt, yt)
 
@@ -185,7 +189,50 @@ def build_population(args, splits, x, y):
         raise SystemExit(f"--population: {e}") from None
 
 
-def main() -> None:
+def resolve_splits(args, arch_cfg) -> tuple:
+    """The per-client cut layers: ``--splits``, else the family's depths
+    (an arch's exit layers) cycled over ``--clients``."""
+    if args.splits:
+        splits = tuple(int(s) for s in args.splits.split(","))
+    elif arch_cfg is not None:
+        cuts = tuple(sorted(arch_cfg.exit_layers))   # the valid cut layers
+        splits = tuple(cuts[i % len(cuts)] for i in range(args.clients))
+    else:
+        splits = tuple(DEFAULT_SPLITS[args.model][i % 3]
+                       for i in range(args.clients))
+    if len(splits) != args.clients:
+        raise SystemExit(f"--splits names {len(splits)} clients but "
+                         f"--clients is {args.clients}")
+    if arch_cfg is not None:
+        bad = sorted(set(splits) - set(arch_cfg.exit_layers))
+        if bad:
+            raise SystemExit(
+                f"--splits {bad} are not exit boundaries of "
+                f"{arch_cfg.name}; valid cut layers: "
+                f"{sorted(arch_cfg.exit_layers)}")
+    return splits
+
+
+def new_session(args, model, parts, splits, mesh=None, population=None):
+    """A fresh ``TrainSession`` from the CLI knobs (``parts`` is ignored
+    when a client ``population`` supplies the data)."""
+    splitee_cfg = SplitEEConfig(profile=HeteroProfile(splits),
+                                strategy=args.strategy,
+                                aggregate_every=args.aggregate_every,
+                                entropy_threshold=args.tau)
+    opt_cfg = OptimizerConfig(
+        lr=args.lr, warmup_steps=0,
+        total_steps=max(args.rounds * args.local_epochs, 1) + 16)
+    return TrainSession.from_config(
+        model, splitee_cfg, opt_cfg,
+        None if population is not None else parts,
+        batch_size=args.batch,
+        engine=args.engine, seed=args.seed, mesh=mesh,
+        grad_mode=args.grad_mode, recipe=args.recipe,
+        population=population)
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", default="mlp", choices=["mlp", "resnet"])
     ap.add_argument("--arch", default="",
@@ -193,8 +240,9 @@ def main() -> None:
                          "qwen3-moe-235b-a22b) through BackboneSplitModel; "
                          "overrides --model")
     ap.add_argument("--smoke", action="store_true",
-                    help="with --arch: use the reduced smoke() config "
-                         "instead of the full-scale config()")
+                    help="use the reduced smoke() config instead of the "
+                         "full-scale config() (--arch, and --model resnet: "
+                         "width 0.125 instead of the paper's 1.0)")
     ap.add_argument("--seq-len", type=int, default=16,
                     help="with --arch: synthetic token sequence length")
     ap.add_argument("--clients", type=int, default=4)
@@ -281,32 +329,20 @@ def main() -> None:
                          "exceeds its deadline and is masked out of "
                          "aggregation")
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv=None) -> None:
+    args = build_parser().parse_args(argv)
 
     # before any jax computation: join the multi-host cluster so every
     # mesh below spans the global device list
     distributed_mod.maybe_initialize(_DIST)
+    enable_compile_cache()
     coordinator = distributed_mod.is_coordinator()
 
     arch_cfg = resolve_arch_config(args)
-    if args.splits:
-        splits = tuple(int(s) for s in args.splits.split(","))
-    elif arch_cfg is not None:
-        cuts = tuple(sorted(arch_cfg.exit_layers))   # the valid cut layers
-        splits = tuple(cuts[i % len(cuts)] for i in range(args.clients))
-    else:
-        splits = tuple(DEFAULT_SPLITS[args.model][i % 3]
-                       for i in range(args.clients))
-    if len(splits) != args.clients:
-        raise SystemExit(f"--splits names {len(splits)} clients but "
-                         f"--clients is {args.clients}")
-    if arch_cfg is not None:
-        bad = sorted(set(splits) - set(arch_cfg.exit_layers))
-        if bad:
-            raise SystemExit(
-                f"--splits {bad} are not exit boundaries of "
-                f"{arch_cfg.name}; valid cut layers: "
-                f"{sorted(arch_cfg.exit_layers)}")
+    splits = resolve_splits(args, arch_cfg)
 
     resuming = bool(args.resume and args.checkpoint_dir and glob.glob(
         os.path.join(args.checkpoint_dir, "ckpt-*.json")))
@@ -329,14 +365,6 @@ def main() -> None:
             mesh = None
     except ValueError as e:
         raise SystemExit(f"--lanes: {e}") from None
-
-    splitee_cfg = SplitEEConfig(profile=HeteroProfile(splits),
-                                strategy=args.strategy,
-                                aggregate_every=args.aggregate_every,
-                                entropy_threshold=args.tau)
-    opt_cfg = OptimizerConfig(
-        lr=args.lr, warmup_steps=0,
-        total_steps=max(args.rounds * args.local_epochs, 1) + 16)
 
     resumed = False
     if resuming:
@@ -369,13 +397,8 @@ def main() -> None:
                     f"--resume mismatch: checkpoint was written with "
                     f"{knob}={want!r} but this run has {knob}={have!r}")
     else:
-        session = TrainSession.from_config(
-            model, splitee_cfg, opt_cfg,
-            None if population is not None else parts,
-            batch_size=args.batch,
-            engine=args.engine, seed=args.seed, mesh=mesh,
-            grad_mode=args.grad_mode, recipe=args.recipe,
-            population=population)
+        session = new_session(args, model, parts, splits, mesh=mesh,
+                              population=population)
 
     what = (f"arch={args.arch}{' (smoke)' if args.smoke else ''} "
             f"[{model.name}]" if args.arch else f"model={args.model}")

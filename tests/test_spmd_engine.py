@@ -334,8 +334,9 @@ def test_spmd_matches_reference_in_process():
 def test_spmd_explicit_mesh_roundtrip():
     """An explicitly supplied mesh (the session's mesh= argument) is used
     and makes spmd eligible; chunked and single-chunk runs agree."""
-    n = len(jax.devices())
-    mesh = jax.make_mesh((n, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+
+    mesh = make_host_mesh((len(jax.devices()), 1))
     parts = _blob_parts(4, n=640)
     _, one = _session("spmd", parts, mesh=mesh)
     _, many = _session("spmd", parts, mesh=mesh)
