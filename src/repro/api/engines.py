@@ -31,6 +31,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple, Type
 
 import numpy as np
 
+from repro import obs
 from repro.config import OptimizerConfig, SplitEEConfig
 from repro.data.pipeline import batch_iterator, effective_batch_size
 from repro.optim import make_schedule
@@ -165,6 +166,11 @@ class Engine:
         if reason:
             raise ValueError(reason)
         self.ctx = ctx
+        #: the current run's program spans (``repro.obs``)
+        self.phases = obs.Phases()
+        #: host seconds and count of each program span in the most recent
+        #: :meth:`run`: ``{name: {"s": seconds, "n": count}}``
+        self.last_run_stats: Dict[str, Dict[str, float]] = {}
 
     @classmethod
     def supports(cls, ctx: SessionContext) -> Optional[str]:
@@ -175,8 +181,25 @@ class Engine:
     def run(self, state, rounds: int, local_epochs: int = 1,
             log_every: int = 0, chunk_rounds: int = 0):
         """Train ``rounds`` rounds from ``state``; returns
-        ``(new_state, [RoundMetrics])``.  Must not mutate ``state``."""
+        ``(new_state, [RoundMetrics])``.  Must not mutate ``state``.  The
+        run is the ``repro.train`` span; :attr:`last_run_stats` holds its
+        spans when it returns or raises."""
+        self.phases = obs.Phases()
+        try:
+            with self.span("repro.train"):
+                return self._run(state, rounds, local_epochs, log_every,
+                                 chunk_rounds)
+        finally:
+            self.last_run_stats = self.phases.as_dict()
+
+    def _run(self, state, rounds: int, local_epochs: int, log_every: int,
+             chunk_rounds: int):
+        """The engine's own :meth:`run`."""
         raise NotImplementedError
+
+    def span(self, name: str) -> obs.span:
+        """A program span of the current run."""
+        return obs.span(name, self.phases)
 
 
 _REGISTRY: Dict[str, Type[Engine]] = {}

@@ -241,23 +241,27 @@ class FusedEngine(Engine):
                 yield self.ctx.data.draw(i)
 
         bufs: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
-        for i in range(self.ctx.N):
-            li, j = self._lane_pos[i]
-            it = drawn(i)
-            first = next(it)          # fixes the staged shapes/dtypes
-            if li not in bufs:
-                x0, y0 = first
-                k = self._counts[li]
-                bufs[li] = (
-                    np.empty((rounds, local_epochs, k, *x0.shape), x0.dtype),
-                    np.empty((rounds, local_epochs, k, *y0.shape), y0.dtype))
-            bx, by = bufs[li]
-            prestage_batches(itertools.chain([first], it), rounds,
-                             local_epochs, out=(bx[:, :, j], by[:, :, j]))
-        xs = {li: self._put_batch(bufs[li][0], li)
-              for li in self._cohort_lis}
-        ys = {li: self._put_batch(bufs[li][1], li)
-              for li in self._cohort_lis}
+        with self.span("repro.stage.fill"):
+            for i in range(self.ctx.N):
+                li, j = self._lane_pos[i]
+                it = drawn(i)
+                first = next(it)          # fixes the staged shapes/dtypes
+                if li not in bufs:
+                    x0, y0 = first
+                    k = self._counts[li]
+                    bufs[li] = (
+                        np.empty((rounds, local_epochs, k, *x0.shape),
+                                 x0.dtype),
+                        np.empty((rounds, local_epochs, k, *y0.shape),
+                                 y0.dtype))
+                bx, by = bufs[li]
+                prestage_batches(itertools.chain([first], it), rounds,
+                                 local_epochs, out=(bx[:, :, j], by[:, :, j]))
+        with self.span("repro.stage.put"):
+            xs = {li: self._put_batch(bufs[li][0], li)
+                  for li in self._cohort_lis}
+            ys = {li: self._put_batch(bufs[li][1], li)
+                  for li in self._cohort_lis}
         return xs, ys
 
     def _put_mask(self, arr: np.ndarray, li: int) -> jnp.ndarray:
@@ -275,33 +279,35 @@ class FusedEngine(Engine):
         ctx = self.ctx
         bufs: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
         masks: Dict[int, np.ndarray] = {}
-        for li in self._cohort_lis:
-            x0, y0 = ctx.client_data[self._lanes[li][0]]
-            k, B = self._counts[li], ctx.batch_size
-            bufs[li] = (
-                np.zeros((rounds, local_epochs, k, B, *x0.shape[1:]),
-                         x0.dtype),
-                np.zeros((rounds, local_epochs, k, B, *y0.shape[1:]),
-                         y0.dtype))
-            masks[li] = np.zeros((rounds, k), np.float32)
-        stats = []
-        for r in range(rounds):
-            plan, slot_batches = ctx.pop_cursor.next_round(local_epochs)
-            stats.append((plan.num_active, plan.num_stragglers))
-            for e, drawn in slot_batches.items():
-                li, j = self._lane_pos[e]
-                masks[li][r, j] = 1.0
-                bx, by = bufs[li]
-                for ei, (x, y) in enumerate(drawn):
-                    bx[r, ei, j] = x
-                    by[r, ei, j] = y
+        with self.span("repro.stage.fill"):
+            for li in self._cohort_lis:
+                x0, y0 = ctx.client_data[self._lanes[li][0]]
+                k, B = self._counts[li], ctx.batch_size
+                bufs[li] = (
+                    np.zeros((rounds, local_epochs, k, B, *x0.shape[1:]),
+                             x0.dtype),
+                    np.zeros((rounds, local_epochs, k, B, *y0.shape[1:]),
+                             y0.dtype))
+                masks[li] = np.zeros((rounds, k), np.float32)
+            stats = []
+            for r in range(rounds):
+                plan, slot_batches = ctx.pop_cursor.next_round(local_epochs)
+                stats.append((plan.num_active, plan.num_stragglers))
+                for e, drawn in slot_batches.items():
+                    li, j = self._lane_pos[e]
+                    masks[li][r, j] = 1.0
+                    bx, by = bufs[li]
+                    for ei, (x, y) in enumerate(drawn):
+                        bx[r, ei, j] = x
+                        by[r, ei, j] = y
         self._plan_stats.append(stats)
-        xs = {li: self._put_batch(bufs[li][0], li)
-              for li in self._cohort_lis}
-        ys = {li: self._put_batch(bufs[li][1], li)
-              for li in self._cohort_lis}
-        ms = {li: self._put_mask(masks[li], li)
-              for li in self._cohort_lis}
+        with self.span("repro.stage.put"):
+            xs = {li: self._put_batch(bufs[li][0], li)
+                  for li in self._cohort_lis}
+            ys = {li: self._put_batch(bufs[li][1], li)
+                  for li in self._cohort_lis}
+            ms = {li: self._put_mask(masks[li], li)
+                  for li in self._cohort_lis}
         return xs, ys, ms
 
     def _round_stage_bytes(self, local_epochs: int) -> int:
@@ -427,7 +433,8 @@ class FusedEngine(Engine):
 
     def _chunk_metrics(self, t0: int, n: int, closs, sloss,
                        log_every: int) -> List[RoundMetrics]:
-        closs, sloss = self._host_losses(closs, sloss)       # one sync/chunk
+        with self.span("repro.fused.loss_sync"):             # one sync/chunk
+            closs, sloss = self._host_losses(closs, sloss)
         # population: the staging producer queued this chunk's per-round
         # (active, straggler) counts before the chunk became consumable
         stats = self._plan_stats.popleft() if self._plan_stats else None
@@ -445,9 +452,9 @@ class FusedEngine(Engine):
         return metrics
 
     # ------------------------------------------------------------ training
-    def run(self, state: TrainState, rounds: int, local_epochs: int = 1,
-            log_every: int = 0, chunk_rounds: int = 0
-            ) -> Tuple[TrainState, List[RoundMetrics]]:
+    def _run(self, state: TrainState, rounds: int, local_epochs: int = 1,
+             log_every: int = 0, chunk_rounds: int = 0
+             ) -> Tuple[TrainState, List[RoundMetrics]]:
         """``chunk_rounds`` bounds how many rounds of pre-staged data are
         resident at once (0 = auto: budget-sized chunks, subdivided for the
         staging pipeline — chunking never changes the trajectory, see
@@ -474,7 +481,8 @@ class FusedEngine(Engine):
         fn = self._chunk_fn(local_epochs)
         clients, copts = list(state.clients), list(state.client_opts)
         servers, sopts = list(state.servers), list(state.server_opts)
-        carry = self._stack_carry(clients, copts, servers, sopts)
+        with self.span("repro.fused.stack_carry"):
+            carry = self._stack_carry(clients, copts, servers, sopts)
         t0 = int(state.round)
 
         stage = (self._stage_population_chunk if population
@@ -482,17 +490,18 @@ class FusedEngine(Engine):
         self._plan_stats.clear()
         pipeline = StagedChunkPipeline(
             lambda n: stage(n, local_epochs), plan,
-            depth=self.pipeline_depth, overlap=overlap)
+            depth=self.pipeline_depth, overlap=overlap, phases=self.phases)
         metrics: List[RoundMetrics] = []
         pending = None                  # (chunk start round, n, closs, sloss)
         try:
             t = t0
             for n in plan:
                 staged = pipeline.get()
-                ts = self._put_ts(t, n)
                 # async dispatch: this chunk's scan starts on device while
                 # the producer stages the next chunk ...
-                carry, (closs, sloss) = fn(carry, ts, *staged)
+                with self.span("repro.fused.dispatch"):
+                    ts = self._put_ts(t, n)
+                    carry, (closs, sloss) = fn(carry, ts, *staged)
                 # ... and only then does the host block on the PREVIOUS
                 # chunk's losses (syncing this chunk's would serialize the
                 # whole loop again)
@@ -523,8 +532,9 @@ class FusedEngine(Engine):
                 "stragglers_per_round": strags,
             }
 
-        self._unstack_carry(self._fetch_carry(carry), clients, copts,
-                            servers, sopts)
+        with self.span("repro.fused.unstack_carry"):
+            self._unstack_carry(self._fetch_carry(carry), clients, copts,
+                                servers, sopts)
         new_state = state.replace(
             clients=tuple(clients), client_opts=tuple(copts),
             servers=tuple(servers), server_opts=tuple(sopts),

@@ -66,9 +66,9 @@ class ReferenceEngine(Engine):
         return self._sstep[li]
 
     # ------------------------------------------------------------ training
-    def run(self, state: TrainState, rounds: int, local_epochs: int = 1,
-            log_every: int = 0, chunk_rounds: int = 0
-            ) -> Tuple[TrainState, List]:
+    def _run(self, state: TrainState, rounds: int, local_epochs: int = 1,
+             log_every: int = 0, chunk_rounds: int = 0
+             ) -> Tuple[TrainState, List]:
         """``chunk_rounds`` is accepted for engine-interface uniformity and
         ignored — the reference engine is round-by-round by construction."""
         ctx = self.ctx
@@ -80,8 +80,9 @@ class ReferenceEngine(Engine):
 
         for r in range(rounds):
             t = t0 + r
-            lr = ctx.schedule(t)
-            lr_server = lr / ctx.server_lr_div
+            with self.span("repro.ref.schedule"):
+                lr = ctx.schedule(t)
+                lr_server = lr / ctx.server_lr_div
             closses, slosses = [], []
 
             for i, li in enumerate(ctx.profile.split_layers):
@@ -92,21 +93,25 @@ class ReferenceEngine(Engine):
                 server, sopt = servers[sidx], sopts[sidx]
 
                 for _ in range(local_epochs):
-                    x, y = ctx.data.draw(i)
-                    x, y = jnp.asarray(x), jnp.asarray(y)
+                    with self.span("repro.ref.input"):
+                        x, y = ctx.data.draw(i)
+                        x, y = jnp.asarray(x), jnp.asarray(y)
                     # client-side training (Alg. 1/2 lines 6-11)
-                    tr, st, copt, h, closs = cstep(client["trainable"],
-                                                   client["state"], copt,
-                                                   x, y, lr)
+                    with self.span("repro.ref.client_step"):
+                        tr, st, copt, h, closs = cstep(client["trainable"],
+                                                       client["state"], copt,
+                                                       x, y, lr)
                     client = {"trainable": tr, "state": st}
                     # server-side training on h_i (lines 12-16)
-                    h = jax.lax.stop_gradient(h)
-                    str_, sst, sopt, sloss = sstep(server["trainable"],
-                                                   server["state"], sopt,
-                                                   h, y, lr_server)
+                    with self.span("repro.ref.server_step"):
+                        h = jax.lax.stop_gradient(h)
+                        str_, sst, sopt, sloss = sstep(server["trainable"],
+                                                       server["state"], sopt,
+                                                       h, y, lr_server)
                     server = {"trainable": str_, "state": sst}
-                    closses.append(float(closs))
-                    slosses.append(float(sloss))
+                    with self.span("repro.ref.loss_sync"):
+                        closses.append(float(closs))
+                        slosses.append(float(sloss))
 
                 clients[i], copts[i] = client, copt
                 servers[sidx], sopts[sidx] = server, sopt
@@ -115,11 +120,12 @@ class ReferenceEngine(Engine):
             if (ctx.strategy == "averaging"
                     and (t + 1) % ctx.cfg.aggregate_every == 0):
                 splits = list(ctx.profile.split_layers)
-                trainables = cross_layer_aggregate(
-                    [s["trainable"] for s in servers], splits)
-                states = cross_layer_aggregate(
-                    [s["state"] for s in servers], splits,
-                    extra_shared_keys=())
+                with self.span("repro.ref.eq1"):
+                    trainables = cross_layer_aggregate(
+                        [s["trainable"] for s in servers], splits)
+                    states = cross_layer_aggregate(
+                        [s["state"] for s in servers], splits,
+                        extra_shared_keys=())
                 servers = [{"trainable": tr, "state": st}
                            for tr, st in zip(trainables, states)]
 

@@ -32,6 +32,7 @@ def _mean_trees(trees: Sequence[Any]) -> Any:
                         .astype(xs[0].dtype) / n, *trees)
 
 
+@jax.named_scope("eq1")
 def cross_layer_aggregate(server_models: Sequence[Dict[str, Any]],
                           split_layers: Sequence[int],
                           extra_shared_keys: Sequence[str] = ("head",),
@@ -60,6 +61,7 @@ def cross_layer_aggregate(server_models: Sequence[Dict[str, Any]],
     return out
 
 
+@jax.named_scope("eq1")
 def stacked_cross_layer_aggregate(stacked: Dict[int, Dict[str, Any]],
                                   counts: Dict[int, int]
                                   ) -> Dict[int, Dict[str, Any]]:
@@ -100,6 +102,7 @@ def stacked_cross_layer_aggregate(stacked: Dict[int, Dict[str, Any]],
     return out
 
 
+@jax.named_scope("eq1")
 def masked_stacked_cross_layer_aggregate(stacked: Dict[int, Dict[str, Any]],
                                          masks: Dict[int, Any],
                                          counts: Dict[int, int]

@@ -167,11 +167,13 @@ class BackboneSplitModel(_StackMixin):
         enc = self._enc_for(trainable, h.shape[0])
         shared_p = trainable.get("shared_attn")
         aux = jnp.zeros((), jnp.float32)
-        for si in range(len(trainable["segments"])):
-            h, a = self._apply_segment(trainable["segments"][si], si, h,
-                                       positions, enc, shared_p)
-            aux = aux + a
-        logits = heads_mod.exit_head(trainable["out"], h, self.cfg)
+        with jax.named_scope("segment"):
+            for si in range(len(trainable["segments"])):
+                h, a = self._apply_segment(trainable["segments"][si], si, h,
+                                           positions, enc, shared_p)
+                aux = aux + a
+        with jax.named_scope("exit_head"):
+            logits = heads_mod.exit_head(trainable["out"], h, self.cfg)
         return h, logits[:, -1, :], aux
 
     def _server_run(self, trainable, h, li: int):
@@ -182,11 +184,13 @@ class BackboneSplitModel(_StackMixin):
         shared_p = trainable.get("shared_attn")
         h = h.astype(self.cfg.dtype)
         aux = jnp.zeros((), jnp.float32)
-        for si in range(b + 1, len(self.plan)):
-            h, a = self._apply_segment(trainable[f"seg{si}"], si, h,
-                                       positions, enc, shared_p)
-            aux = aux + a
-        logits = heads_mod.lm_head(trainable["head"], h, self.cfg)
+        with jax.named_scope("segment"):
+            for si in range(b + 1, len(self.plan)):
+                h, a = self._apply_segment(trainable[f"seg{si}"], si, h,
+                                           positions, enc, shared_p)
+                aux = aux + a
+        with jax.named_scope("head"):
+            logits = heads_mod.lm_head(trainable["head"], h, self.cfg)
         return logits[:, -1, :], aux
 
     def client_forward(self, trainable, state, x, train: bool

@@ -96,17 +96,22 @@ class ResNetSplitModel(_StackMixin):
 
     def client_forward(self, trainable, state, x, train: bool
                        ) -> Tuple[jnp.ndarray, jnp.ndarray, Any]:
-        h, new_state = rn.resnet_features(trainable["layers"], state, x,
-                                          self.cfg, end_layer=len(trainable["layers"]),
-                                          train=train)
-        logits = rn.client_head_forward(trainable["out"], h)
+        with jax.named_scope("segment"):
+            h, new_state = rn.resnet_features(
+                trainable["layers"], state, x, self.cfg,
+                end_layer=len(trainable["layers"]), train=train)
+        with jax.named_scope("exit_head"):
+            logits = rn.client_head_forward(trainable["out"], h)
         return h, logits, new_state
 
     def server_forward(self, trainable, state, h, li: int, train: bool
                        ) -> Tuple[jnp.ndarray, Any]:
-        feats, new_state = rn.resnet_features(trainable, state, h, self.cfg,
-                                              start_layer=li, train=train)
-        logits = rn.head_forward(trainable["head"], feats)
+        with jax.named_scope("segment"):
+            feats, new_state = rn.resnet_features(trainable, state, h,
+                                                  self.cfg, start_layer=li,
+                                                  train=train)
+        with jax.named_scope("head"):
+            logits = rn.head_forward(trainable["head"], feats)
         return logits, new_state
 
 

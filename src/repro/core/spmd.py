@@ -328,9 +328,11 @@ def make_cohort_train_step(model, opt_cfg, li: int,
     sloss_fn = server_loss_fn(model, li)
 
     def joint_loss(ctr, strv, cst, sst, x, y):
-        closs, (h, new_cst) = closs_fn(ctr, cst, x, y)
+        with jax.named_scope("client"):
+            closs, (h, new_cst) = closs_fn(ctr, cst, x, y)
         h = jax.lax.stop_gradient(h)
-        sloss, new_sst = sloss_fn(strv, sst, h, y)
+        with jax.named_scope("server"):
+            sloss, new_sst = sloss_fn(strv, sst, h, y)
         return closs + sloss, (closs, sloss, new_cst, new_sst)
 
     def combined(client, copt, server, sopt, x, y, lr, lr_s):

@@ -14,6 +14,11 @@ facade; this module keeps what all engines share:
     math in ``eq1`` grad mode.
   * :class:`RoundMetrics` — the per-round metric record.
 
+Each side's loss and gradient run under ``jax.named_scope("client")`` /
+``("server")``, so every engine's device ops carry the side in their HLO
+``op_name`` (backward ops as ``transpose(jvp(client))``); Adam and Eq. (1)
+carry ``adam`` and ``eq1`` (docs/ENGINES.md, "Reading a run").
+
 Gradients never flow from server to client (``h_i`` enters the server step
 as data), and every model is initialized from the same random seed via the
 adapters in ``core/splitee.py`` (paper §III-B).
@@ -103,8 +108,9 @@ def make_client_step(model, opt_cfg: OptimizerConfig) -> Callable:
     loss_fn = client_loss_fn(model)
 
     def step(trainable, state, opt, x, y, lr):
-        (loss, (h, new_state)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(trainable, state, x, y)
+        with jax.named_scope("client"):
+            (loss, (h, new_state)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(trainable, state, x, y)
         trainable, opt = adam_update(trainable, grads, opt, opt_cfg, lr)
         return trainable, new_state, opt, h, loss
 
@@ -118,8 +124,9 @@ def make_server_step(model, opt_cfg: OptimizerConfig, li: int) -> Callable:
     loss_fn = server_loss_fn(model, li)
 
     def step(trainable, state, opt, h, y, lr):
-        (loss, new_state), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(trainable, state, h, y)
+        with jax.named_scope("server"):
+            (loss, new_state), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(trainable, state, h, y)
         trainable, opt = adam_update(trainable, grads, opt, opt_cfg, lr)
         return trainable, new_state, opt, loss
 

@@ -33,10 +33,11 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 import warnings
 from dataclasses import dataclass, field
-from typing import Any, Callable, Sequence
+from typing import Any, Callable, Optional, Sequence
+
+from repro import obs
 
 
 @dataclass
@@ -85,16 +86,22 @@ class StagedChunkPipeline:
     ``release()`` must be called once per completed chunk (it frees a
     buffer slot for the producer); ``close()`` is idempotent and must
     run on every exit path so the producer thread never outlives the
-    run."""
+    run.
+
+    The producer's staging is the ``repro.stage.produce`` span and the
+    consumer's wait the ``repro.stage.wait`` span (``repro.obs``), added
+    to ``phases`` when given; :class:`StageStats` is timed by them."""
 
     def __init__(self, stage_fn: Callable[[int], Any], plan: Sequence[int],
-                 *, depth: int = 2, overlap: bool = True):
+                 *, depth: int = 2, overlap: bool = True,
+                 phases: Optional[obs.Phases] = None):
         if depth < 2:
             raise ValueError(f"pipeline depth must be >= 2 (one chunk in "
                              f"compute plus >= 1 staged ahead); got {depth}")
         self._stage_fn = stage_fn
         self._plan = list(plan)
         self._overlap = overlap
+        self._phases = phases
         self.stats = StageStats(overlap=overlap)
         self._serial_next = 0
         if not overlap:
@@ -113,16 +120,15 @@ class StagedChunkPipeline:
                 self._slots.acquire()
                 if self._cancelled.is_set():
                     return
-                t0 = time.perf_counter()
-                chunk = self._stage_fn(n)
-                dt = time.perf_counter() - t0
+                with obs.span("repro.stage.produce", self._phases) as sp:
+                    chunk = self._stage_fn(n)
                 # re-check after the (possibly long) stage_fn: once close()
                 # has cancelled us, the consumer may already be reading
                 # stats — stop mutating shared state and drawing from the
                 # session's data cursor
                 if self._cancelled.is_set():
                     return
-                self.stats.stage_s += dt
+                self.stats.stage_s += sp.seconds
                 self._q.put((chunk, None))
         except BaseException as e:                        # noqa: BLE001
             # surface staging failures at the consumer's next get(), with
@@ -135,16 +141,15 @@ class StagedChunkPipeline:
         if not self._overlap:
             n = self._plan[self._serial_next]
             self._serial_next += 1
-            t0 = time.perf_counter()
-            chunk = self._stage_fn(n)
-            dt = time.perf_counter() - t0
-            self.stats.stage_s += dt
-            self.stats.wait_s += dt       # serial: every staging is waited
+            with obs.span("repro.stage.wait", self._phases) as sp:
+                chunk = self._stage_fn(n)
+            self.stats.stage_s += sp.seconds
+            self.stats.wait_s += sp.seconds  # serial: every staging is waited
             self.stats.chunks += 1
             return chunk
-        t0 = time.perf_counter()
-        chunk, err = self._q.get()
-        self.stats.wait_s += time.perf_counter() - t0
+        with obs.span("repro.stage.wait", self._phases) as sp:
+            chunk, err = self._q.get()
+        self.stats.wait_s += sp.seconds
         if err is not None:
             self.close()
             raise err

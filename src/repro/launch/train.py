@@ -66,6 +66,7 @@ import jax
 import numpy as np
 
 from repro import configs as configs_mod
+from repro import obs
 from repro.api import TrainSession
 from repro.config import HeteroProfile, OptimizerConfig, SplitEEConfig
 from repro.configs import resnet18_cifar
@@ -452,6 +453,10 @@ def main(argv=None) -> None:
               f"({remaining / dt:.2f} rounds/s)  "
               f"client_loss {m.client_loss:.4f}  "
               f"server_loss {m.server_loss:.4f}")
+        shares = obs.phase_shares(session.engine.last_run_stats)
+        print("phases of the last engine run (% of repro.train): "
+              + "  ".join(f"{k[len('repro.'):]} {v:.1f}%"
+                          for k, v in shares))
         if population is not None:
             # aggregate over THIS invocation's rounds (the engine's
             # last_participation_stats covers only the final save_every

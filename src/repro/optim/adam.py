@@ -46,6 +46,7 @@ def global_norm(tree: Any) -> jnp.ndarray:
                         for l in leaves))
 
 
+@jax.named_scope("adam")
 def adam_update(params: Any, grads: Any, state: AdamState,
                 cfg: OptimizerConfig, lr: jnp.ndarray,
                 lr_scale_tree: Optional[Any] = None):
