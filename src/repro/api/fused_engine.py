@@ -94,6 +94,10 @@ class FusedEngine(Engine):
             i: (li, j) for li in self._cohort_lis
             for j, i in enumerate(self._lanes[li])}
         self._chunk_fns: Dict[int, Callable] = {}
+        #: the carry swap's two programs, one dispatch each per run (jit's
+        #: own cache keys them on the state's tree structure)
+        self._stack_fn = jax.jit(self._stack_lanes)
+        self._unstack_fn = jax.jit(self._split_lanes)
         #: staging/overlap accounting for the most recent :meth:`run`
         #: (``data.staging.StageStats.as_dict`` — the bench's overlap leg
         #: reads it)
@@ -390,7 +394,11 @@ class FusedEngine(Engine):
             done += n
         return plan
 
-    def _stack_carry(self, clients, copts, servers, sopts):
+    def _stack_lanes(self, clients, copts, servers, sopts):
+        """Per-client states -> the ``{li: (client, copt, server, sopt)}``
+        cohort carry, each leaf stacked along a leading lane axis.  Traced
+        once into the stack program, so the adapter's ``stack_clients``
+        keeps its say over how clients and servers stack."""
         model = self.ctx.model
         carry = {}
         for li in self._cohort_lis:
@@ -403,12 +411,30 @@ class FusedEngine(Engine):
             )
         return carry
 
+    def _split_lanes(self, carry):
+        """The cohort carry -> ``{li: (clients, copts, servers, sopts)}``,
+        each a list of the cohort's per-lane trees."""
+        return {li: tuple(unstack_pytrees(t, self._counts[li])
+                          for t in carry[li])
+                for li in self._cohort_lis}
+
+    def _stack_carry(self, clients, copts, servers, sopts):
+        """Stack the run's carry in one compiled dispatch, not one per leaf
+        group (a ResNet-18 Table-IV carry has about 500).  Inputs are not
+        donated: the caller's ``TrainState`` still owns them."""
+        return self._stack_fn(clients, copts, servers, sopts)
+
     def _unstack_carry(self, carry, clients, copts, servers, sopts):
+        """Split the run-final carry back into per-client states.  A device
+        carry is sliced in one compiled dispatch, not three device ops per
+        leaf; a host carry (the multi-process spmd fetch) is sliced on the
+        host, where numpy views cost nothing and stay host arrays."""
+        on_device = all(isinstance(x, jax.Array)
+                        for x in jax.tree.leaves(carry))
+        parts = (self._unstack_fn if on_device else self._split_lanes)(carry)
         for li in self._cohort_lis:
-            lanes = self._lanes[li]
-            cs, co, ss, so = (unstack_pytrees(t, len(lanes))
-                              for t in carry[li])
-            for j, i in enumerate(lanes):
+            cs, co, ss, so = parts[li]
+            for j, i in enumerate(self._lanes[li]):
                 clients[i], copts[i] = cs[j], co[j]
                 servers[i], sopts[i] = ss[j], so[j]
 

@@ -7,7 +7,9 @@ import pytest
 
 from repro.api import TrainSession
 from repro.config import HeteroProfile, OptimizerConfig, SplitEEConfig
-from repro.core.splitee import MLPSplitModel, stack_pytrees, unstack_pytrees
+from repro.core.splitee import (MLPSplitModel, ResNetSplitModel, stack_pytrees,
+                                 unstack_pytrees)
+from repro.models.resnet import ResNetConfig
 
 TOL = 1e-5
 
@@ -179,6 +181,111 @@ def test_stack_unstack_roundtrip():
     _assert_trees_close(stack_pytrees(clients), stacked)
     for a, b in zip(unstack_pytrees(stacked, 3), back):
         _assert_trees_close(a, b)
+
+
+# ---------------------------------------------------------------------------
+# the carry swap: one compiled program each way, bit-identical to the eager
+# per-leaf composition
+# ---------------------------------------------------------------------------
+
+
+def _trained_session(kind):
+    """A fused session one round in, so BatchNorm statistics and Adam step
+    counts have left their initial values."""
+    if kind == "mlp":
+        tr, _ = _make("fused", "averaging")
+    else:
+        rng = np.random.default_rng(0)
+        x = rng.normal(size=(96, 8, 8, 3)).astype(np.float32)
+        y = rng.integers(0, 3, 96).astype(np.int32)
+        model = ResNetSplitModel(ResNetConfig(num_classes=3, width_mult=0.125,
+                                              image_size=8), seed=0)
+        tr = TrainSession.from_config(
+            model, SplitEEConfig(profile=HeteroProfile((2, 2, 3)),
+                                 strategy="averaging"),
+            OptimizerConfig(lr=1e-3, total_steps=10),
+            [(x[i::3], y[i::3]) for i in range(3)], batch_size=16,
+            engine="fused")
+    tr.train(1)
+    return tr
+
+
+def _state_lists(state):
+    return (list(state.clients), list(state.client_opts),
+            list(state.servers), list(state.server_opts))
+
+
+def _assert_trees_identical(a, b):
+    assert jax.tree.structure(a) == jax.tree.structure(b)
+    for u, v in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        assert u.dtype == v.dtype and u.shape == v.shape
+        np.testing.assert_array_equal(np.asarray(u), np.asarray(v))
+
+
+@pytest.mark.parametrize("kind", ["mlp", "resnet"])
+def test_stack_program_matches_eager_composition(kind):
+    tr = _trained_session(kind)
+    eng = tr.engine
+    args = _state_lists(tr.state)
+    carry = eng._stack_carry(*args)
+    assert eng._stack_fn._cache_size() == 1        # the one-program path
+    # the same composition, run eagerly leaf by leaf
+    _assert_trees_identical(carry, eng._stack_lanes(*args))
+    for li in eng._cohort_lis:
+        assert int(np.asarray(carry[li][1].step)[0]) == 1
+        if kind == "resnet":
+            assert jax.tree.leaves(carry[li][0]["state"])       # BatchNorm
+            assert jax.tree.leaves(carry[li][2]["state"])
+
+
+@pytest.mark.parametrize("kind", ["mlp", "resnet"])
+def test_unstack_program_matches_unstack_pytrees(kind):
+    tr = _trained_session(kind)
+    eng = tr.engine
+    args = _state_lists(tr.state)
+    carry = eng._stack_carry(*args)
+    outs = tuple([None] * tr.ctx.N for _ in range(4))
+    eng._unstack_carry(carry, *outs)
+    assert eng._unstack_fn._cache_size() == 1      # the one-program path
+    for li in eng._cohort_lis:
+        lanes = eng._lanes[li]
+        want = [unstack_pytrees(t, len(lanes)) for t in carry[li]]
+        for j, i in enumerate(lanes):
+            for k in range(4):
+                _assert_trees_identical(outs[k][i], want[k][j])
+                _assert_trees_identical(outs[k][i], args[k][i])
+
+
+def test_host_carry_unstacks_to_numpy():
+    """A host carry (what the multi-process spmd fetch returns) is sliced
+    on the host: per-client leaves stay numpy, and no program runs."""
+    tr = _trained_session("mlp")
+    eng = tr.engine
+    args = _state_lists(tr.state)
+    host = jax.device_get(eng._stack_carry(*args))
+    outs = tuple([None] * tr.ctx.N for _ in range(4))
+    traced = eng._unstack_fn._cache_size()
+    eng._unstack_carry(host, *outs)
+    assert eng._unstack_fn._cache_size() == traced
+    for k in range(4):
+        for i in range(tr.ctx.N):
+            leaves = jax.tree.leaves(outs[k][i])
+            # a lane of a stacked scalar reads back as a numpy scalar
+            assert all(isinstance(x, (np.ndarray, np.generic))
+                       for x in leaves)
+            _assert_trees_identical(outs[k][i], args[k][i])
+
+
+def test_successive_runs_trace_each_swap_program_once():
+    tr, _ = _make("fused", "averaging")
+    eng = tr.engine
+    for _ in range(2):
+        tr.train(2)
+        stats = eng.last_run_stats
+        assert stats["repro.fused.stack_carry"]["n"] == 1
+        assert stats["repro.fused.unstack_carry"]["n"] == 1
+    assert eng._stack_fn._cache_size() == 1
+    assert eng._unstack_fn._cache_size() == 1
 
 
 # ---------------------------------------------------------------------------
